@@ -119,7 +119,10 @@ def rm_solve(scn: Scenario, bids: jnp.ndarray, *, mask=None, sweep_fn=None):
         cum = jnp.cumsum(inc, axis=1)
         fill = jnp.clip(spare - (cum - inc), 0.0, inc)          # (Nc, N)
         sum_fill = jnp.sum(fill, axis=1)
-        p_fill = fill @ p_sorted
+        # full f32 on the TPU (its default matmul precision rounds the
+        # operands to bf16); a no-op on the CPU
+        p_fill = jnp.matmul(fill, p_sorted,
+                            precision=jax.lax.Precision.HIGHEST)
     else:
         fill, sum_fill, p_fill = sweep_fn(inc, spare, p_sorted)
 

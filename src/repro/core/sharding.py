@@ -18,7 +18,7 @@ Managers per game, independent games per lane) maps directly onto hardware:
   grow, shrink or compact between solves stay valid on a resident mesh
   (the repad is mesh-aware by construction);
 * :func:`solve_sharded_batch` runs Algorithm 4.1 under
-  ``jax.experimental.shard_map.shard_map``: each device iterates a local
+  ``jax.shard_map``: each device iterates a local
   ``while_loop`` over its own lane slice, with the per-lane convergence
   freezing and :class:`~repro.core.game.BatchWarmStart` warm starts of the
   unsharded solver fully preserved.
@@ -46,7 +46,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core import game
@@ -395,8 +394,8 @@ def _resident_solver(mesh: Mesh, eps_bar: float, lam: float, max_iters: int,
         return game._solve_batch_core(batch, eps_bar, lam, max_iters,
                                       sweep_fn, init, iter_fn=iter_fn)
 
-    sharded = shard_map(local_solve, mesh=mesh, in_specs=(spec, spec),
-                        out_specs=spec, check_rep=False)
+    sharded = jax.shard_map(local_solve, mesh=mesh, in_specs=(spec, spec),
+                            out_specs=spec, check_vma=False)
     return jax.jit(sharded, donate_argnums=(1,))
 
 
@@ -479,9 +478,9 @@ def _sharded_solver(mesh: Mesh, eps_bar: float, lam: float, max_iters: int,
                                       sweep_fn, init[0] if init else None,
                                       iter_fn=iter_fn)
 
-    sharded = shard_map(local_solve, mesh=mesh,
-                        in_specs=(spec, spec) if with_init else (spec,),
-                        out_specs=spec, check_rep=False)
+    sharded = jax.shard_map(local_solve, mesh=mesh,
+                            in_specs=(spec, spec) if with_init else (spec,),
+                            out_specs=spec, check_vma=False)
     return jax.jit(sharded)
 
 

@@ -6,9 +6,11 @@ hoists the iteration-invariant tensors out of the while_loop and ``step``
 runs one fused Alg. 4.1 inner iteration.  Off-TPU the fused middle is the
 pure-jnp formulation of ``ref.py`` (already one fused XLA region — the
 win over the unfused chain is the hoisted prep and, under
-``dtype_policy="f32_checked"``, the halved element width); on TPU (or
-with ``force_pallas=True``, which tests use in interpret mode) the
-O(B x Nc x N) middle is the single Pallas launch of ``kernel.py``.
+``dtype_policy="f32_checked"``, the halved element width); on TPU the
+O(B x Nc x N) middle is the single compiled Pallas launch of
+``kernel.py`` — never interpret mode there — and off-TPU
+``force_pallas=True`` (which the tests use) runs the same kernel in
+interpret mode.
 """
 from __future__ import annotations
 
@@ -18,29 +20,30 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.gnep_iter import ref
-from repro.kernels.gnep_iter.kernel import fused_iter_sweep
+from repro.kernels.gnep_iter.kernel import fused_iter_call
 
 
-def _middle_pallas(prep: ref.IterPrep, cand, bids_sorted):
+def _middle_pallas(prep: ref.IterPrep, cand, bids_sorted, *, interpret):
     """Pallas middle for ``ref.iter_step``: one launch, then the best-row
-    pick.  TPU computes in f32 (no f64 VMEM); off-TPU interpret mode
-    keeps the input dtype so the f64 differential tests stay exact.  The
-    best-row pick is a one-hot contraction, honoring ``iter_step``'s
-    no-gather invariant (the contraction has one nonzero per row, so it
-    moves the kernel's bits unchanged)."""
-    on_tpu = jax.default_backend() == "tpu"
+    pick.  Compiled for the TPU it computes in f32 (Mosaic has no f64
+    vector path); interpret mode keeps the input dtype so the f64
+    differential tests stay exact.  The best-row pick is a one-hot
+    contraction over the kernel's class-major fill, honoring
+    ``iter_step``'s no-gather invariant (the contraction has one nonzero
+    per row, so it moves the kernel's bits unchanged)."""
     dt = bids_sorted.dtype
 
     def cast(x):
-        return x.astype(jnp.float32) if on_tpu else x
+        return x if interpret else x.astype(jnp.float32)
 
-    fill, _, best, rho = fused_iter_sweep(
+    fill_cm, _, best, rho = fused_iter_call(
         cast(bids_sorted), cast(prep.inc_max_sorted), cast(prep.p_sorted),
         cast(cand), cast(prep.spare), cast(prep.rho_bar),
         cast(prep.sum_r_low), cast(prep.p_r_low), cast(prep.const),
-        interpret=not on_tpu)
-    best_onehot = best[:, None] == jnp.arange(fill.shape[1])
-    fill_best = jnp.sum(jnp.where(best_onehot[:, :, None], fill, 0.0), axis=1)
+        interpret=interpret)
+    best_onehot = best[:, None] == jnp.arange(fill_cm.shape[2])
+    fill_best = jnp.sum(jnp.where(best_onehot[:, None, :], fill_cm, 0.0),
+                        axis=2)
     return fill_best.astype(dt), best, rho.astype(dt)
 
 
@@ -135,6 +138,10 @@ def make_fused_iter_fn(force_pallas: bool = False) -> FusedIterFn:
         The plug-point object for ``SolverConfig(iter_fn=...)`` /
         ``solve_distributed_batch(iter_fn=...)``.
     """
-    on_tpu = jax.default_backend() == "tpu"
-    middle = _middle_pallas if (on_tpu or force_pallas) else None
+    if jax.default_backend() == "tpu":
+        middle = functools.partial(_middle_pallas, interpret=False)
+    elif force_pallas:
+        middle = functools.partial(_middle_pallas, interpret=True)
+    else:
+        middle = None
     return FusedIterFn(f"gnep_iter(force_pallas={force_pallas})", middle)

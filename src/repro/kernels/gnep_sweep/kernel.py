@@ -2,16 +2,21 @@
 
 At N classes the exact RM solve is an O(N^2) masked running-sum: for each of
 ~N candidate prices, a greedy knapsack fill in fixed p-order.  This kernel
-tiles it (BC candidates x BN classes per step); the running per-candidate
-cumulative fill is VMEM scratch carried across the sequential class axis, so
-each (BC, BN) tile does a cumsum + clip on the VPU with one pass over HBM.
+tiles it (BN classes x BC candidates per step); the running per-candidate
+cumulative fill is VMEM scratch carried across the sequential class axis,
+and inside a tile the classes advance one at a time — the same column
+recurrence as ``repro.kernels.gnep_iter`` — so each step is a handful of
+VPU ops on a ``(1, BC)`` candidate row, with one pass over HBM.
 
-Grid: (Nc/BC, N/BN) with the class axis sequential.
+``rm_sweep_batched`` runs the grid (B, Nc/BC, N/BN), so the price sweep of
+a whole ScenarioBatch is ONE kernel launch: batch and candidate axes are
+parallel, the class axis stays sequential per (batch, candidate-tile).
+``rm_sweep`` is the same launch over a batch of one.
 
-``rm_sweep_batched`` extends the grid to (B, Nc/BC, N/BN) so the price sweep
-of a whole ScenarioBatch is ONE kernel launch: batch and candidate axes are
-parallel, the class axis stays sequential per (batch, candidate-tile) and
-carries the same VMEM running-sum scratch.
+Layout (what Mosaic accepts on the TPU): the increments enter class-major,
+``(N, Nc)``, so a class is a row of candidates loaded from an aligned group
+of 8 rows; the penalty rates and the slack are SMEM scalars.  Everything
+runs in f32.
 """
 from __future__ import annotations
 
@@ -20,99 +25,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from repro.kernels.gnep_iter.kernel import I32_ZERO, ROW_GROUP, tiling
 
 
 def _kernel(inc_ref, spare_ref, p_ref, fill_ref, sumf_ref, pf_ref,
-            cum_scr, sacc_scr, pacc_scr, *, n_blocks):
-    ji = pl.program_id(1)
-
-    @pl.when(ji == 0)
-    def _init():
-        cum_scr[...] = jnp.zeros_like(cum_scr)
-        sacc_scr[...] = jnp.zeros_like(sacc_scr)
-        pacc_scr[...] = jnp.zeros_like(pacc_scr)
-
-    inc = inc_ref[...].astype(jnp.float32)            # (BC, BN)
-    spare = spare_ref[0, 0]
-    pv = p_ref[...].astype(jnp.float32)               # (BN,)
-
-    cum_in = cum_scr[...]                             # (BC,)
-    local_cum = jnp.cumsum(inc, axis=1)
-    before = cum_in[:, None] + local_cum - inc        # filled before each cls
-    fill = jnp.clip(spare - before, 0.0, inc)
-    fill_ref[...] = fill.astype(fill_ref.dtype)
-
-    cum_scr[...] = cum_in + local_cum[:, -1]
-    sacc_scr[...] = sacc_scr[...] + jnp.sum(fill, axis=1)
-    pacc_scr[...] = pacc_scr[...] + fill @ pv
-
-    @pl.when(ji == n_blocks - 1)
-    def _final():
-        sumf_ref[...] = sacc_scr[...].astype(sumf_ref.dtype)
-        pf_ref[...] = pacc_scr[...].astype(pf_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("block_c", "block_n",
-                                             "interpret"))
-def rm_sweep(inc, spare, p_sorted, *, block_c=128, block_n=512,
-             interpret=False):
-    """inc: (Nc, N) f32; spare: scalar; p_sorted: (N,).
-    Returns (fill (Nc, N), sum_fill (Nc,), p_fill (Nc,))."""
-    Nc, N = inc.shape
-    block_c = min(block_c, Nc)
-    block_n = min(block_n, N)
-    # pad to tile multiples (padding classes have inc=0 -> no effect)
-    pc = (-Nc) % block_c
-    pn = (-N) % block_n
-    inc_p = jnp.pad(inc, ((0, pc), (0, pn)))
-    p_p = jnp.pad(p_sorted, (0, pn))
-    Ncp, Np = Nc + pc, N + pn
-    n_blocks = Np // block_n
-    spare_arr = jnp.asarray(spare, jnp.float32).reshape(1, 1)
-
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        try:
-            kwargs["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary"))
-        except Exception:
-            pass
-    scratch = ([_VMEM((block_c,), jnp.float32)] * 3 if _VMEM is not None
-               else [pl.ANY] * 3)
-    fill, sumf, pf = pl.pallas_call(
-        functools.partial(_kernel, n_blocks=n_blocks),
-        grid=(Ncp // block_c, n_blocks),
-        in_specs=[
-            pl.BlockSpec((block_c, block_n), lambda ci, ji: (ci, ji)),
-            pl.BlockSpec((1, 1), lambda ci, ji: (0, 0)),
-            pl.BlockSpec((block_n,), lambda ci, ji: (ji,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_c, block_n), lambda ci, ji: (ci, ji)),
-            pl.BlockSpec((block_c,), lambda ci, ji: (ci,)),
-            pl.BlockSpec((block_c,), lambda ci, ji: (ci,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Ncp, Np), inc.dtype),
-            jax.ShapeDtypeStruct((Ncp,), jnp.float32),
-            jax.ShapeDtypeStruct((Ncp,), jnp.float32),
-        ],
-        scratch_shapes=scratch,
-        interpret=interpret,
-        **kwargs,
-    )(inc_p, spare_arr, p_p)
-    return fill[:Nc, :N], sumf[:Nc], pf[:Nc]
-
-
-def _kernel_batched(inc_ref, spare_ref, p_ref, fill_ref, sumf_ref, pf_ref,
-                    cum_scr, sacc_scr, pacc_scr, *, n_blocks):
+            cum_scr, sacc_scr, pacc_scr, *, n_blocks, block_c, block_n,
+            group):
     ji = pl.program_id(2)
 
     @pl.when(ji == 0)
@@ -121,75 +41,103 @@ def _kernel_batched(inc_ref, spare_ref, p_ref, fill_ref, sumf_ref, pf_ref,
         sacc_scr[...] = jnp.zeros_like(sacc_scr)
         pacc_scr[...] = jnp.zeros_like(pacc_scr)
 
-    inc = inc_ref[0].astype(jnp.float32)              # (BC, BN)
-    spare = spare_ref[0, 0]                           # this batch lane's slack
-    pv = p_ref[0].astype(jnp.float32)                 # (BN,)
+    spare = spare_ref[0, 0, 0]                        # this lane's slack
+    rows = jax.lax.broadcasted_iota(jnp.int32, (group, block_c), 0)
 
-    cum_in = cum_scr[...]                             # (BC,)
-    local_cum = jnp.cumsum(inc, axis=1)
-    before = cum_in[:, None] + local_cum - inc        # filled before each cls
-    fill = jnp.clip(spare - before, 0.0, inc)
-    fill_ref[0] = fill.astype(fill_ref.dtype)
+    # cumsum along the class axis, as a recurrence: class j's fill is what
+    # is left of the slack after every earlier class's increment, clipped
+    # to its own increment.
+    def _classes(g, carry):
+        cum, sacc, pacc = carry
+        base = pl.multiple_of(g * jnp.int32(group), group)
+        block = inc_ref[0, pl.ds(base, group), :]     # (group, BC)
+        tile = jnp.zeros_like(block)
+        for k in range(group):
+            inc = block[k:k + 1, :]                   # (1, BC)
+            cum = cum + inc
+            fill = jnp.clip(spare - (cum - inc), 0.0, inc)
+            tile = jnp.where(rows == k, fill, tile)
+            sacc = sacc + fill
+            pacc = pacc + fill * p_ref[0, 0, base + k]
+        fill_ref[0, pl.ds(base, group), :] = tile
+        return cum, sacc, pacc
 
-    cum_scr[...] = cum_in + local_cum[:, -1]
-    sacc_scr[...] = sacc_scr[...] + jnp.sum(fill, axis=1)
-    pacc_scr[...] = pacc_scr[...] + fill @ pv
+    cum, sacc, pacc = jax.lax.fori_loop(
+        jnp.int32(0), jnp.int32(block_n // group), _classes,
+        (cum_scr[...], sacc_scr[...], pacc_scr[...]))
+    cum_scr[...] = cum
+    sacc_scr[...] = sacc
+    pacc_scr[...] = pacc
 
     @pl.when(ji == n_blocks - 1)
     def _final():
-        sumf_ref[0] = sacc_scr[...].astype(sumf_ref.dtype)
-        pf_ref[0] = pacc_scr[...].astype(pf_ref.dtype)
+        sumf_ref[0] = sacc_scr[...]
+        pf_ref[0] = pacc_scr[...]
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "block_n",
                                              "interpret"))
-def rm_sweep_batched(inc, spare, p_sorted, *, block_c=128, block_n=512,
+def rm_sweep_batched(inc, spare, p_sorted, *, block_c=512, block_n=512,
                      interpret=False):
     """Batched RM price sweep: B instances in one kernel launch.
 
-    inc: (B, Nc, N) f32; spare: (B,); p_sorted: (B, N).
-    Returns (fill (B, Nc, N), sum_fill (B, Nc), p_fill (B, Nc))."""
+    inc: (B, Nc, N); spare: (B,); p_sorted: (B, N).  Computes in f32.
+    Returns (fill (B, Nc, N) in ``inc``'s dtype, sum_fill (B, Nc) f32,
+    p_fill (B, Nc) f32)."""
     B, Nc, N = inc.shape
-    block_c = min(block_c, Nc)
-    block_n = min(block_n, N)
-    # pad to tile multiples (padding classes have inc=0 -> no effect)
-    pc = (-Nc) % block_c
-    pn = (-N) % block_n
-    inc_p = jnp.pad(inc, ((0, 0), (0, pc), (0, pn)))
-    p_p = jnp.pad(p_sorted, ((0, 0), (0, pn)))
-    Ncp, Np = Nc + pc, N + pn
+    f32 = jnp.float32
+    block_c, block_n, Ncp, Np = tiling(Nc, N, block_c, block_n, interpret)
     n_blocks = Np // block_n
-    spare_arr = jnp.asarray(spare, jnp.float32).reshape(B, 1)
+    group = ROW_GROUP if block_n % ROW_GROUP == 0 else 1
+    # class-major, padded to tile multiples (padding has inc = 0: no effect)
+    inc_cm = jnp.pad(jnp.swapaxes(inc.astype(f32), 1, 2),
+                     ((0, 0), (0, Np - N), (0, Ncp - Nc)))   # (B, Np, Ncp)
+    p_p = jnp.pad(p_sorted.astype(f32), ((0, 0), (0, Np - N)))[:, None, :]
+    spare_arr = jnp.asarray(spare, f32).reshape(B, 1, 1)
 
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        try:
-            kwargs["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
-        except Exception:
-            pass
-    scratch = ([_VMEM((block_c,), jnp.float32)] * 3 if _VMEM is not None
-               else [pl.ANY] * 3)
+    smem = pltpu.SMEM
     fill, sumf, pf = pl.pallas_call(
-        functools.partial(_kernel_batched, n_blocks=n_blocks),
+        functools.partial(_kernel, n_blocks=n_blocks, block_c=block_c,
+                          block_n=block_n, group=group),
         grid=(B, Ncp // block_c, n_blocks),
         in_specs=[
-            pl.BlockSpec((1, block_c, block_n), lambda bi, ci, ji: (bi, ci, ji)),
-            pl.BlockSpec((1, 1), lambda bi, ci, ji: (bi, 0)),
-            pl.BlockSpec((1, block_n), lambda bi, ci, ji: (bi, ji)),
+            pl.BlockSpec((1, block_n, block_c),
+                         lambda bi, ci, ji: (bi, ji, ci)),
+            pl.BlockSpec((1, 1, 1),
+                         lambda bi, ci, ji: (bi, I32_ZERO, I32_ZERO),
+                         memory_space=smem),
+            pl.BlockSpec((1, 1, block_n),
+                         lambda bi, ci, ji: (bi, I32_ZERO, ji),
+                         memory_space=smem),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_c, block_n), lambda bi, ci, ji: (bi, ci, ji)),
-            pl.BlockSpec((1, block_c), lambda bi, ci, ji: (bi, ci)),
-            pl.BlockSpec((1, block_c), lambda bi, ci, ji: (bi, ci)),
+            pl.BlockSpec((1, block_n, block_c),
+                         lambda bi, ci, ji: (bi, ji, ci)),
+            pl.BlockSpec((1, 1, block_c),
+                         lambda bi, ci, ji: (bi, I32_ZERO, ci)),
+            pl.BlockSpec((1, 1, block_c),
+                         lambda bi, ci, ji: (bi, I32_ZERO, ci)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Ncp, Np), inc.dtype),
-            jax.ShapeDtypeStruct((B, Ncp), jnp.float32),
-            jax.ShapeDtypeStruct((B, Ncp), jnp.float32),
+            jax.ShapeDtypeStruct((B, Np, Ncp), f32),
+            jax.ShapeDtypeStruct((B, 1, Ncp), f32),
+            jax.ShapeDtypeStruct((B, 1, Ncp), f32),
         ],
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((1, block_c), f32)] * 3,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
-    )(inc_p, spare_arr, p_p)
-    return fill[:, :Nc, :N], sumf[:, :Nc], pf[:, :Nc]
+    )(inc_cm, spare_arr, p_p)
+    fill = jnp.swapaxes(fill[:, :N, :Nc], 1, 2).astype(inc.dtype)
+    return fill, sumf[:, 0, :Nc], pf[:, 0, :Nc]
+
+
+def rm_sweep(inc, spare, p_sorted, *, block_c=512, block_n=512,
+             interpret=False):
+    """inc: (Nc, N); spare: scalar; p_sorted: (N,).  One instance of
+    :func:`rm_sweep_batched`.
+    Returns (fill (Nc, N), sum_fill (Nc,), p_fill (Nc,))."""
+    fill, sumf, pf = rm_sweep_batched(
+        inc[None], jnp.reshape(jnp.asarray(spare), (1,)), p_sorted[None],
+        block_c=block_c, block_n=block_n, interpret=interpret)
+    return fill[0], sumf[0], pf[0]

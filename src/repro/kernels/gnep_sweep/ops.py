@@ -11,6 +11,9 @@ from repro.kernels.gnep_sweep.ref import reference, reference_batched
 
 
 def sweep(inc, spare, p_sorted, *, force_pallas=False):
+    """Single-instance sweep for ``rm_solve(sweep_fn=...)``: the compiled
+    kernel on TPU (never interpret mode there), interpret mode off it
+    under ``force_pallas``, the jnp reference otherwise."""
     on_tpu = jax.default_backend() == "tpu"
     if on_tpu or force_pallas:
         return rm_sweep(inc.astype(jnp.float32), spare,

@@ -29,10 +29,13 @@ import argparse
 import asyncio
 import sys
 
-# --resident shards tenant state over a lane mesh; on a bare CPU the forced
-# host-device topology must be configured before jax initializes a backend
+from repro._env import force_host_devices, use_compile_cache
+
+# both knobs must be set before jax initializes: the persistent compile
+# cache, and (--resident shards tenant state over a lane mesh) the forced
+# host-device topology of a bare CPU
+use_compile_cache()
 if "--resident" in sys.argv or "--devices" in sys.argv:
-    from repro._env import force_host_devices
     force_host_devices()
 
 import jax
@@ -126,6 +129,8 @@ async def run_server(engine, args):
 
 
 def main(argv=None):
+    """Run the daemon driver (or the wire server); return the exit status:
+    non-zero when a flush raised, or when ``--conformance`` could not run."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--listen", type=str, default=None, metavar="HOST:PORT",
                     help="serve the daemon over the wire protocol instead "
@@ -168,7 +173,7 @@ def main(argv=None):
             asyncio.run(run_server(make_engine(args), args))
         except KeyboardInterrupt:
             pass
-        return None
+        return 0
 
     engine = make_engine(args)
     traces = make_traces(args)
@@ -183,12 +188,20 @@ def main(argv=None):
     print(f"[allocd] admission latency p50 {rep['admission_p50_ms']:.1f} ms"
           f" / p99 {rep['admission_p99_ms']:.1f} ms; "
           f"flushes {rep['flushes']:.0f}; rejected {rep['rejected']:.0f} "
-          f"(penalty {rep['rejection_cost']:.2f})")
+          f"(penalty {rep['rejection_cost']:.2f}); "
+          f"flush errors {rep['flush_errors']:.0f}")
 
+    status = 0
+    if rep["flush_errors"]:
+        print(f"[allocd] FAILED: {rep['flush_errors']:.0f} flushes raised "
+              "and their tickets were failed", file=sys.stderr)
+        status = 1
     if args.conformance:
         if rep["rejected"]:
-            print("[allocd] conformance: SKIPPED (rejections under "
-                  "backpressure change the delivered trace)")
+            print("[allocd] conformance: NOT RUN (rejections under "
+                  "backpressure change the delivered trace)",
+                  file=sys.stderr)
+            status = 1
         else:
             for name, trace in traces.items():
                 t = int(name.split("-")[1])
@@ -197,8 +210,8 @@ def main(argv=None):
                 assert_reports_bitequal(name, daemon.reports(name), want)
             print(f"[allocd] conformance: OK ({args.tenants} tenants "
                   "bit-equal to offline replay)")
-    return rep
+    return status
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -22,10 +22,13 @@ import argparse
 import json
 import sys
 
-# --shard solves on a lane mesh; on a bare CPU the forced host-device
-# topology must be configured before jax initializes a backend
+from repro._env import force_host_devices, use_compile_cache
+
+# both knobs must be set before jax initializes: the persistent compile
+# cache, and (--shard solves on a lane mesh) the forced host-device
+# topology of a bare CPU
+use_compile_cache()
 if "--shard" in sys.argv or "--devices" in sys.argv:
-    from repro._env import force_host_devices
     force_host_devices()
 
 from repro.core import (PlanSpec, SolverConfig, VMTier, lane_mesh,

@@ -536,7 +536,8 @@ class AllocDaemon:
             ``admission_p50_ms`` / ``admission_p99_ms`` (scheduled-arrival
             to flush-completion latency percentiles), plus counters
             (``submitted``, ``accepted``, ``rejected``,
-            ``rejection_cost``, ``events_folded``, ``flushes``).
+            ``rejection_cost``, ``events_folded``, ``flushes``, and
+            ``flush_errors`` — flushes that raised, whose tickets failed).
         """
         folded = sum(t.session.events_folded
                      for t in self._tenants.values())
@@ -552,6 +553,7 @@ class AllocDaemon:
             "rejection_cost": float(self.rejection_cost),
             "events_folded": float(folded),
             "flushes": float(flushes),
+            "flush_errors": float(self.flush_errors),
             "elapsed_s": float(elapsed),
             "events_per_sec": float(folded / elapsed) if elapsed else 0.0,
             "admission_p50_ms": float(np.percentile(lat, 50) * 1e3)

@@ -464,3 +464,45 @@ def test_diurnal_times_profile():
     # (core/traces.py may carry more — tests/test_planning.py pins the set)
     assert {"poisson", "flash", "diurnal"} <= set(ARRIVAL_PROFILES)
     assert ARRIVAL_PROFILES["diurnal"] is diurnal_times
+
+
+def test_poisoned_flush_is_reported_and_fails_the_launcher(monkeypatch,
+                                                           tmp_path):
+    """A flush that raises fails its tickets, keeps the daemon alive, is
+    counted in ``report()["flush_errors"]``, and makes the launcher exit
+    non-zero instead of printing throughput over failed epochs."""
+    from repro.core.engine import WindowSession
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    from repro.launch import allocd as launch
+
+    def poisoned(self):
+        raise RuntimeError("poisoned epoch")
+
+    monkeypatch.setattr(WindowSession, "flush", poisoned)
+
+    async def run():
+        daemon = AllocDaemon(make_engine(flush_k=2), queue_limit=None)
+        daemon.add_tenant("t", make_window(0))
+        await daemon.start()
+        tickets = [daemon.submit("t", arrival(s)) for s in range(4)]
+        await daemon.shutdown(drain=True)
+        return daemon, tickets
+
+    daemon, tickets = asyncio.run(run())
+    assert all(tk.cancelled for tk in tickets)
+    assert daemon.report()["flush_errors"] == 2.0
+    assert launch.main(["--tenants", "1", "--lanes", "2", "--classes", "3",
+                        "--events", "4", "--flush-every", "2"]) == 1
+
+
+def test_launcher_fails_when_conformance_cannot_run(monkeypatch, tmp_path):
+    """``--conformance`` under backpressure rejections cannot compare the
+    delivered trace with the offline one: the launcher says so and exits
+    non-zero rather than passing a check it skipped."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    from repro.launch import allocd as launch
+
+    assert launch.main(["--tenants", "2", "--lanes", "2", "--classes", "3",
+                        "--events", "6", "--queue-limit", "1",
+                        "--rate", "1e9", "--conformance"]) == 1
