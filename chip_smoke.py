@@ -50,7 +50,9 @@ ROOT = Path(__file__).resolve().parent
 
 ENGINE_LANES, ENGINE_CLASSES = 64, 1000      # paper Figs. 2/3, top size
 TENANTS, TENANT_LANES, TENANT_CLASSES = 8, 4, 100
-TENANT_N_MAX, TENANT_EVENTS, FLUSH_K = 200, 32, 8
+TENANT_EVENTS, FLUSH_K = 32, 8
+#: The window width ``repro.launch.allocd`` opens for its tenants.
+TENANT_N_MAX = 2 * TENANT_CLASSES
 REF_LANES = 4
 #: Default vs fused solves: ULPs of float32 at the lane's capacity R (the
 #: magnitude of the running sums both reorder).
@@ -85,10 +87,14 @@ class CompileMeter:
         return self.compile_s, self.hits, self.misses
 
 
+def passed(what):
+    print(f"[chip_smoke]   ok: {what}", flush=True)
+
+
 def check(ok, what):
     if not ok:
         raise RuntimeError(f"check failed: {what}")
-    print(f"[chip_smoke]   ok: {what}", flush=True)
+    passed(what)
 
 
 def timed(fn):
@@ -169,69 +175,38 @@ def engine_phase(seed, iter_fn):
               f"lane {b} gap to (P3) optimum {gap:.2e} >= -{F32_TOL:g}")
 
 
-def tenant_lanes(seed, t):
-    import jax
-    from repro.core import sample_scenario
-    key = jax.random.PRNGKey(seed)
-    return [sample_scenario(jax.random.fold_in(key, t * 97 + lane),
-                            TENANT_CLASSES, capacity_factor=1.3)
-            for lane in range(TENANT_LANES)]
+def served_args(seed, *flags):
+    """The served phase's tenants and engine, as flags of
+    ``python -m repro.launch.allocd``."""
+    from repro.launch import allocd
+    return allocd.parse_args([
+        "--tenants", str(TENANTS), "--lanes", str(TENANT_LANES),
+        "--classes", str(TENANT_CLASSES), "--events", str(TENANT_EVENTS),
+        "--flush-every", str(FLUSH_K), "--seed", str(seed), *flags])
 
 
-def tenant_trace(seed, t, lanes):
-    from repro.core import AdmissionWindow, sample_event_trace
-    return sample_event_trace(seed + 7919 * t,
-                              AdmissionWindow(lanes, n_max=TENANT_N_MAX),
-                              TENANT_EVENTS)
-
-
-def served_engine(config=None):
-    from repro.core import (CapacityEngine, FlushPolicy, Policies,
-                            RoundingPolicy, SolverConfig)
-    return CapacityEngine(config or SolverConfig(),
-                          Policies(flush=FlushPolicy(max_events=FLUSH_K),
-                                   rounding=RoundingPolicy(enabled=False)))
-
-
-def reports_bitequal(got, want):
-    import jax
-    import numpy as np
-    if len(got) != len(want):
-        return False
-    for a, b in zip(got, want):
-        la = jax.tree_util.tree_flatten(a.fractional)[0]
-        lb = jax.tree_util.tree_flatten(b.fractional)[0]
-        if len(la) != len(lb) or not all(
-                np.array_equal(np.asarray(x), np.asarray(y))
-                for x, y in zip(la, lb)):
-            return False
-        if not np.array_equal(np.asarray(a.mask), np.asarray(b.mask)):
-            return False
-    return True
-
-
-async def _serve(engine, seed):
+async def _serve(args, engine, traces):
+    from repro.launch.allocd import make_lanes
     from repro.serving.allocd import AllocDaemon
     from repro.serving.client import AllocClient
     from repro.serving.server import AllocServer
 
-    daemon = AllocDaemon(engine, queue_limit=4096)
+    daemon = AllocDaemon(engine, queue_limit=args.queue_limit)
     server = AllocServer(daemon, host="127.0.0.1", port=0)
     await server.start()
     host, port = server.address
 
     async def tenant(t):
         name = f"tenant-{t}"
-        lanes = tenant_lanes(seed, t)
-        trace = tenant_trace(seed, t, lanes)
         client = await AllocClient.connect(host, port)
         try:
-            await client.register_tenant(name, lanes, n_max=TENANT_N_MAX)
-            tickets = [client.offer(name, ev) for ev in trace]
+            await client.register_tenant(name, make_lanes(args, t),
+                                         n_max=TENANT_N_MAX)
+            tickets = [client.offer(name, ev) for ev in traces[name]]
             acks = [await tk.ack() for tk in tickets]
             await client.drain()
             results = [await tk.result() for tk in tickets]
-            return name, lanes, trace, acks, results, client.reports(name)
+            return acks, results, client.reports(name)
         finally:
             await client.close()
 
@@ -243,13 +218,17 @@ async def _serve(engine, seed):
 
 
 def served_phase(seed):
-    """8 wire tenants against one in-process AllocServer, then conformance
-    of each against an offline replay on the same engine."""
-    from repro.core import AdmissionWindow
+    """8 wire tenants against one in-process AllocServer, then the
+    launcher's ``--conformance`` check of each against an offline replay
+    on the same engine."""
+    from repro.launch.allocd import (assert_reports_bitequal, make_engine,
+                                     make_traces, make_window)
 
-    engine = served_engine()
+    args = served_args(seed)
+    engine, traces = make_engine(args), make_traces(args)
     t0 = time.perf_counter()
-    daemon, tenants = asyncio.run(asyncio.wait_for(_serve(engine, seed), 900))
+    daemon, tenants = asyncio.run(
+        asyncio.wait_for(_serve(args, engine, traces), 900))
     seconds = time.perf_counter() - t0
     rep = daemon.report()
     print(f"[chip_smoke] served: {TENANTS} tenants x {TENANT_LANES} lanes x "
@@ -259,18 +238,18 @@ def served_phase(seed):
           f"p99 {rep['admission_p99_ms']:.1f} ms (compile included)",
           flush=True)
     check(all(all(acks) and all(r is not None for r in results)
-              for _, _, _, acks, results, _ in tenants),
+              for acks, results, _ in tenants),
           f"all {TENANTS * TENANT_EVENTS} tickets accepted and resolved")
     check(rep["rejected"] == 0 and rep["flush_errors"] == 0,
           f"rejections {rep['rejected']:.0f}, flush errors "
           f"{rep['flush_errors']:.0f}")
-    for name, lanes, trace, _, _, got in tenants:
-        offline = engine.open_window(AdmissionWindow(lanes,
-                                                     n_max=TENANT_N_MAX))
-        want = list(offline.stream(trace))
-        check(reports_bitequal(got, want),
-              f"{name}: {len(got)} wire flush reports bit-equal to the "
-              "offline replay")
+    for t, (_, _, got) in enumerate(tenants):
+        name = f"tenant-{t}"
+        want = list(engine.open_window(make_window(args, t))
+                    .stream(traces[name]))
+        assert_reports_bitequal(name, got, want)
+        passed(f"{name}: {len(got)} wire flush reports bit-equal to the "
+               "offline replay")
     return seconds
 
 
@@ -280,38 +259,38 @@ def close(a, b):
                        atol=MESH_TOL)
 
 
-def mesh_phase(seed, mesh):
-    """Lane-sharded solve and a resident window session over ``mesh``,
-    each against the same work on one device."""
-    from repro.core import AdmissionWindow, CapacityEngine, SolverConfig
+def mesh_phase(seed, chips):
+    """Lane-sharded solve over a ``chips``-chip lane mesh, and the served
+    phase's first tenant as a resident window session on it (as
+    ``repro.launch.allocd --resident --devices`` runs it), each against
+    the same work on one device."""
+    from repro.core import CapacityEngine, SolverConfig, lane_mesh
+    from repro.launch.allocd import make_engine, make_traces, make_window
 
     _, batch = engine_batch(seed)
     one, t_one = timed(lambda: CapacityEngine(SolverConfig()).solve(batch))
-    shard, t_shard = timed(
-        lambda: CapacityEngine(SolverConfig(mesh=mesh)).solve(batch))
+    shard, t_shard = timed(lambda: CapacityEngine(
+        SolverConfig(mesh=lane_mesh(chips))).solve(batch))
     print(f"[chip_smoke] sharded: {batch.batch_size} lanes x {batch.n_max} "
-          f"classes over {mesh.devices.size} chips {t_shard:.3f} s, one "
+          f"classes over {chips} chips {t_shard:.3f} s, one "
           f"chip {t_one:.3f} s (compile included)", flush=True)
     check(all(close(getattr(shard.fractional, f), getattr(one.fractional, f))
               for f in ("r", "psi", "total", "aux")),
           f"sharded == one device to {MESH_TOL:g}")
 
-    lanes = tenant_lanes(seed, 0)
-    trace = tenant_trace(seed, 0, lanes)
-    sessions = {
-        "one": served_engine(),
-        "resident": served_engine(SolverConfig(mesh=mesh,
-                                               residency="resident"))}
+    args = served_args(seed)
+    trace = make_traces(args)["tenant-0"]
+    flags = {"one": (), "resident": ("--resident", "--devices", str(chips))}
     reports, t_res = {}, 0.0
-    for name, engine in sessions.items():
-        session = engine.open_window(AdmissionWindow(lanes,
-                                                     n_max=TENANT_N_MAX))
+    for name, extra in flags.items():
+        session = make_engine(served_args(seed, *extra)).open_window(
+            make_window(args, 0))
         reports[name], seconds = timed(lambda: list(session.stream(trace)))
         if name == "resident":
             t_res = seconds
             check(session.window.is_resident, "window stayed resident")
     print(f"[chip_smoke] resident: {len(trace)} events, "
-          f"{len(reports['resident'])} flushes over {mesh.devices.size} "
+          f"{len(reports['resident'])} flushes over {chips} "
           f"chips {t_res:.3f} s (compile included)", flush=True)
     check(len(reports["one"]) == len(reports["resident"]) and all(
         close(getattr(a.fractional, f), getattr(b.fractional, f))
@@ -361,9 +340,8 @@ def main(argv=None):
               f"{h - before[1]} hits / {m - before[2]} misses", flush=True)
 
     if args.chips == 4:
-        from repro.core import lane_mesh
         before = meter.snapshot()
-        _, seconds = timed(lambda: mesh_phase(args.seed, lane_mesh(4)))
+        _, seconds = timed(lambda: mesh_phase(args.seed, args.chips))
         report("mesh", before, seconds)
     else:
         from repro.kernels.gnep_iter.ops import make_fused_iter_fn

@@ -65,12 +65,15 @@ def make_engine(args):
                  rounding=RoundingPolicy(enabled=args.round)))
 
 
-def make_window(args, tenant: int) -> AdmissionWindow:
+def make_lanes(args, tenant: int):
     key = jax.random.PRNGKey(args.seed)
-    lanes = [sample_scenario(jax.random.fold_in(key, tenant * 97 + lane),
-                             args.classes, capacity_factor=1.3)
-             for lane in range(args.lanes)]
-    return AdmissionWindow(lanes, n_max=2 * args.classes)
+    return [sample_scenario(jax.random.fold_in(key, tenant * 97 + lane),
+                            args.classes, capacity_factor=1.3)
+            for lane in range(args.lanes)]
+
+
+def make_window(args, tenant: int) -> AdmissionWindow:
+    return AdmissionWindow(make_lanes(args, tenant), n_max=2 * args.classes)
 
 
 def make_traces(args):
@@ -128,9 +131,7 @@ async def run_server(engine, args):
         await server.close(drain=True)
 
 
-def main(argv=None):
-    """Run the daemon driver (or the wire server); return the exit status:
-    non-zero when a flush raised, or when ``--conformance`` could not run."""
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--listen", type=str, default=None, metavar="HOST:PORT",
                     help="serve the daemon over the wire protocol instead "
@@ -166,8 +167,13 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--conformance", action="store_true",
                     help="assert bit-equality against offline replays")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def main(argv=None):
+    """Run the daemon driver (or the wire server); return the exit status:
+    non-zero when a flush raised, or when ``--conformance`` could not run."""
+    args = parse_args(argv)
     if args.listen is not None:
         try:
             asyncio.run(run_server(make_engine(args), args))
