@@ -54,6 +54,7 @@ from repro.core.rounding import (IntegerSolution, round_solution,
 from repro.core.streaming import AdmissionWindow, FlushPolicy
 from repro.core.types import (ClassArrival, Scenario, ScenarioBatch, SLAEdit,
                               Solution, StreamEvent, stack_scenarios)
+from repro.spans import span
 
 
 class InfeasibleError(RuntimeError):
@@ -914,15 +915,17 @@ class CapacityEngine:
         ``residency='resident'`` config, which makes the window resident on
         first use) takes the zero-resharding resident path."""
         cfg = self.config
-        if not window.is_resident and cfg.residency == "resident":
-            window.make_resident(cfg.mesh)
-        if window.is_resident:
-            if cfg.mesh is not None and cfg.mesh != window.resident_mesh:
-                raise ValueError(
-                    "window is resident on a different mesh than the "
-                    "engine's config.mesh — release_resident or match them")
-            return self._solve_window_resident(window)
-        return self._solve_window_roundtrip(window)
+        with span("engine.solve"):
+            if not window.is_resident and cfg.residency == "resident":
+                window.make_resident(cfg.mesh)
+            if window.is_resident:
+                if cfg.mesh is not None and cfg.mesh != window.resident_mesh:
+                    raise ValueError(
+                        "window is resident on a different mesh than the "
+                        "engine's config.mesh — release_resident or match "
+                        "them")
+                return self._solve_window_resident(window)
+            return self._solve_window_roundtrip(window)
 
     def _solve_window_roundtrip(self,
                                 window: AdmissionWindow) -> WindowSolveReport:
@@ -1278,15 +1281,16 @@ class WindowSession:
             # slot_map describes the PREVIOUS flush's compaction — this
             # no-op flush performed none, so the echo must not carry it
             return replace(self._last_report, slot_map=None)
-        self.drain()
-        report_map = None
-        comp = self.engine.policies.compaction
-        if (comp.occupancy is not None
-                and self.window.occupancy < comp.occupancy):
-            counts = self.window.n_classes
-            widest = max(int(counts.max()), 1)
-            target = max(int(np.ceil(comp.headroom * widest)), widest)
-            report_map = self.window.compact(n_max=target)
+        with span("session.apply"):
+            self.drain()
+            report_map = None
+            comp = self.engine.policies.compaction
+            if (comp.occupancy is not None
+                    and self.window.occupancy < comp.occupancy):
+                counts = self.window.n_classes
+                widest = max(int(counts.max()), 1)
+                target = max(int(np.ceil(comp.headroom * widest)), widest)
+                report_map = self.window.compact(n_max=target)
         report = self.engine._solve_window(self.window)
         report.slot_map = report_map
         self.flushes += 1

@@ -49,6 +49,7 @@ from repro.core.engine import (CapacityEngine, Policies, RoundingPolicy,
 from repro.core.profiles import sample_class_params
 from repro.core.traces import ARRIVAL_PROFILES
 from repro.core.types import Scenario, ScenarioBatch, derive, stack_scenarios
+from repro.spans import span
 from repro.utils import fdtype
 
 
@@ -450,6 +451,22 @@ def _chunk_targets(chunk: int, cfg: SolverConfig) -> int:
     return sharding.padded_lane_count(chunk, cfg.mesh.devices.size)
 
 
+def _stack_chunk(part: Sequence[Candidate], n_max: int, target: int, dtype
+                 ) -> Tuple[ScenarioBatch, int]:
+    """One chunk's solver input: its candidates' scenarios stacked to
+    ``n_max`` classes, inert-lane padded to ``target`` lanes and cast to
+    ``dtype`` (``None`` keeps theirs); returned with its real lane count."""
+    with span("plan.stack"):
+        batch = stack_scenarios([c.scenario for c in part], n_max=n_max)
+        real = batch.batch_size
+        batch = sharding.pad_batch_lanes(batch, target)
+        if dtype is not None:
+            batch = ScenarioBatch(
+                scenarios=_cast_floats(batch.scenarios, dtype),
+                mask=batch.mask, n_classes=batch.n_classes)
+    return batch, real
+
+
 def _solve_cold(candidates: Sequence[Candidate], cfg: SolverConfig,
                 chunk: int, n_max: int):
     """Chunked cold solves through the engine's batched path.
@@ -483,17 +500,18 @@ def _solve_cold(candidates: Sequence[Candidate], cfg: SolverConfig,
     n_chunks = 0
     for start in range(0, len(candidates), chunk):
         part = candidates[start:start + chunk]
-        batch = stack_scenarios([c.scenario for c in part], n_max=n_max)
-        real = batch.batch_size
-        batch = sharding.pad_batch_lanes(batch, target)
-        report = engine.solve(batch, check_feasible=False)
-        sol = report.fractional
-        out["cost"].append(np.asarray(sol.cost)[:real])
-        out["penalty"].append(np.asarray(sol.penalty)[:real])
-        out["total"].append(np.asarray(sol.total)[:real])
-        out["r"].append(np.asarray(sol.r)[:real])
-        out["iters"].append(np.asarray(report.iters)[:real])
-        out["feasible"].append(np.asarray(report.feasible)[:real])
+        with span("plan.chunk", lanes=len(part)):
+            batch, real = _stack_chunk(part, n_max, target,
+                                       cfg.effective_dtype())
+            report = engine.solve(batch, check_feasible=False)
+            sol = report.fractional
+            with span("plan.pull"):
+                out["cost"].append(np.asarray(sol.cost)[:real])
+                out["penalty"].append(np.asarray(sol.penalty)[:real])
+                out["total"].append(np.asarray(sol.total)[:real])
+                out["r"].append(np.asarray(sol.r)[:real])
+                out["iters"].append(np.asarray(report.iters)[:real])
+                out["feasible"].append(np.asarray(report.feasible)[:real])
         n_chunks += 1
     return {k: np.concatenate(v) for k, v in out.items()}, n_chunks
 
@@ -548,29 +566,26 @@ def _solve_warm(spec: PlanSpec, candidates: Sequence[Candidate],
         prev_r = None
         for d in range(D):
             part = [candidates[ci * D + d] for ci in chains]
-            batch = stack_scenarios([c.scenario for c in part], n_max=n_max)
-            real = batch.batch_size
-            batch = sharding.pad_batch_lanes(batch, target)
-            if dt is not None:
-                batch = ScenarioBatch(
-                    scenarios=_cast_floats(batch.scenarios, dt),
-                    mask=batch.mask, n_classes=batch.n_classes)
-            init = game.cold_start(batch)
-            if prev_r is not None:
-                init = init._replace(
-                    r=jnp.where(batch.mask, prev_r, init.r))
-            sol = game.solve_distributed_batch(
-                batch, eps_bar=cfg.eps_bar, lam=cfg.lam,
-                max_iters=cfg.max_iters, sweep_fn=cfg.sweep_fn, init=init,
-                mesh=cfg.mesh, iter_fn=cfg.iter_fn)
-            prev_r = sol.r
-            rows = [c.index for c in part]
-            fields["cost"][rows] = np.asarray(sol.cost)[:real]
-            fields["penalty"][rows] = np.asarray(sol.penalty)[:real]
-            fields["total"][rows] = np.asarray(sol.total)[:real]
-            fields["r"][rows] = np.asarray(sol.r)[:real]
-            fields["iters"][rows] = np.asarray(sol.iters)[:real]
-            fields["feasible"][rows] = np.asarray(sol.feasible)[:real]
+            with span("plan.chunk", lanes=len(part)):
+                batch, real = _stack_chunk(part, n_max, target, dt)
+                init = game.cold_start(batch)
+                if prev_r is not None:
+                    init = init._replace(
+                        r=jnp.where(batch.mask, prev_r, init.r))
+                sol = game.solve_distributed_batch(
+                    batch, eps_bar=cfg.eps_bar, lam=cfg.lam,
+                    max_iters=cfg.max_iters, sweep_fn=cfg.sweep_fn,
+                    init=init, mesh=cfg.mesh, iter_fn=cfg.iter_fn)
+                prev_r = sol.r
+                rows = [c.index for c in part]
+                with span("plan.pull"):
+                    fields["cost"][rows] = np.asarray(sol.cost)[:real]
+                    fields["penalty"][rows] = np.asarray(sol.penalty)[:real]
+                    fields["total"][rows] = np.asarray(sol.total)[:real]
+                    fields["r"][rows] = np.asarray(sol.r)[:real]
+                    fields["iters"][rows] = np.asarray(sol.iters)[:real]
+                    fields["feasible"][rows] = np.asarray(
+                        sol.feasible)[:real]
             n_chunks += 1
     return fields, n_chunks
 

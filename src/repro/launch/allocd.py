@@ -21,7 +21,10 @@ remote processes register tenants and submit events with
 ``repro.serving.client.AllocClient`` (walkthrough:
 ``examples/wire_client.py``).  ``--quota-events`` / ``--quota-lanes``
 set the default per-tenant admission budget applied to wire tenants
-that register without one.
+that register without one.  ``--profiler-port N`` starts JAX's profiler
+server on port N, from which ``jax.profiler`` or xprof captures a trace of
+the live daemon: its ``repro.*`` spans on the device's clock
+(``docs/OPERATIONS.md`` lists them).
 """
 from __future__ import annotations
 
@@ -111,6 +114,8 @@ async def run_daemon(engine, args, traces):
 
 
 async def run_server(engine, args):
+    if args.profiler_port is not None:
+        jax.profiler.start_server(args.profiler_port)
     daemon = AllocDaemon(engine, queue_limit=args.queue_limit)
     quota = None
     if args.quota_events is not None or args.quota_lanes is not None:
@@ -142,6 +147,9 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--quota-lanes", type=int, default=None,
                     help="default TenantQuota.max_lanes for wire tenants "
                          "registering without a quota")
+    ap.add_argument("--profiler-port", type=int, default=None, metavar="N",
+                    help="with --listen: serve JAX profiler captures of "
+                         "the live daemon on port N")
     ap.add_argument("--tenants", type=int, default=4)
     ap.add_argument("--lanes", type=int, default=4)
     ap.add_argument("--classes", type=int, default=5)

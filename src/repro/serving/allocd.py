@@ -45,11 +45,18 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.engine import (CapacityEngine, TenantQuota, WindowSession,
                                WindowSolveReport)
 from repro.core.types import ClassArrival, StreamEvent
+from repro.spans import span
+
+#: Length of the daemon's observability logs (``latencies_s``,
+#: ``fold_log``, ``flush_log``): the most recent events are kept, so an
+#: always-on daemon's memory stays bounded.
+RECENT_EVENTS = 65536
 
 
 def rejection_penalty(event: StreamEvent) -> float:
@@ -179,10 +186,12 @@ class AllocDaemon:
         self._wake: Optional["asyncio.Event"] = None
         self._t_start: Optional[float] = None
         self._t_last_flush: Optional[float] = None
-        # observability (tests + throughput reporting)
-        self.latencies_s: List[float] = []
-        self.fold_log: List[str] = []           # intake order, by tenant
-        self.flush_log: List[Tuple[str, float]] = []  # (tenant, slack) order
+        # observability (tests + throughput reporting), most recent only
+        self.latencies_s: Deque[float] = deque(maxlen=RECENT_EVENTS)
+        # intake order, by tenant; flush order, as (tenant, slack)
+        self.fold_log: Deque[str] = deque(maxlen=RECENT_EVENTS)
+        self.flush_log: Deque[Tuple[str, float]] = deque(
+            maxlen=RECENT_EVENTS)
         self.submitted = 0
         self.rejected = 0
         self.rejection_cost = 0.0
@@ -339,7 +348,8 @@ class AllocDaemon:
             t.rejection_cost += ticket.penalty
             return ticket
         ticket._fut = asyncio.get_running_loop().create_future()
-        t.queue.append(ticket)
+        with span("allocd.enqueue", seq=ticket.seq):
+            t.queue.append(ticket)
         self._queued += 1
         if self._wake is not None:
             self._wake.set()
@@ -419,12 +429,7 @@ class AllocDaemon:
         while t.queue:
             if t.due:
                 self._flush(t)
-            ticket = t.queue.popleft()
-            self._queued -= 1
-            t.inflight.append(ticket)
-            self.fold_log.append(name)
-            if t.session.offer(ticket.event):
-                t.due = True
+            self._fold(t)
         if t.due or t.inflight or t.session.pending:
             self._flush(t)
 
@@ -461,12 +466,7 @@ class AllocDaemon:
                 t = self._tenants[name]
                 if t.due or not t.queue:
                     continue
-                ticket = t.queue.popleft()
-                self._queued -= 1
-                t.inflight.append(ticket)
-                self.fold_log.append(name)
-                if t.session.offer(ticket.event):
-                    t.due = True
+                self._fold(t)
                 worked = True
         due = [t for t in self._tenants.values() if t.due]
         for t in sorted(due, key=lambda t: (t.session.pending_slack(),
@@ -475,35 +475,48 @@ class AllocDaemon:
             worked = True
         return worked
 
+    def _fold(self, t: _Tenant) -> None:
+        """Take the tenant's next queued ticket into its open epoch."""
+        ticket = t.queue.popleft()
+        with span("allocd.fold", seq=ticket.seq):
+            self._queued -= 1
+            t.inflight.append(ticket)
+            self.fold_log.append(t.name)
+            if t.session.offer(ticket.event):
+                t.due = True
+
     def _flush(self, t: _Tenant) -> None:
         tickets, t.inflight = t.inflight, []
-        slack = t.session.pending_slack()
-        try:
-            report = t.session.flush()
-        except Exception as exc:   # poisoned epoch: fail it, stay alive
-            t.session.discard_pending()
+        with span("allocd.flush", tenant=t.name, events=len(tickets)):
+            slack = t.session.pending_slack()
+            try:
+                report = t.session.flush()
+            except Exception as exc:   # poisoned epoch: fail it, stay alive
+                t.session.discard_pending()
+                t.due = False
+                self.flush_errors += 1
+                for ticket in tickets:
+                    ticket.cancelled = True
+                    ticket._fail(exc)
+                if t.on_flush is not None:
+                    t.on_flush(None, tickets)
+                return
+            with span("allocd.device_wait"):
+                jax.block_until_ready(report.fractional)
+            now = time.perf_counter()
+            self._t_last_flush = now
             t.due = False
-            self.flush_errors += 1
-            for ticket in tickets:
-                ticket.cancelled = True
-                ticket._fail(exc)
+            t.reports.append(report)
+            self.flush_log.append((t.name, slack))
+            slots = t.session.last_slots
+            for i, ticket in enumerate(tickets):
+                ticket.slot = slots[i] if i < len(slots) else None
+                ticket.report = report
+                ticket.t_done = now
+                self.latencies_s.append(now - ticket.t_submit)
+                ticket._resolve(report)
             if t.on_flush is not None:
-                t.on_flush(None, tickets)
-            return
-        now = time.perf_counter()
-        self._t_last_flush = now
-        t.due = False
-        t.reports.append(report)
-        self.flush_log.append((t.name, slack))
-        slots = t.session.last_slots
-        for i, ticket in enumerate(tickets):
-            ticket.slot = slots[i] if i < len(slots) else None
-            ticket.report = report
-            ticket.t_done = now
-            self.latencies_s.append(now - ticket.t_submit)
-            ticket._resolve(report)
-        if t.on_flush is not None:
-            t.on_flush(report, tickets)
+                t.on_flush(report, tickets)
 
     def _final_flushes(self) -> None:
         """Graceful-drain tail: flush every trailing partial epoch."""
@@ -534,7 +547,9 @@ class AllocDaemon:
         dict
             ``events_per_sec`` (folded events over active wall time),
             ``admission_p50_ms`` / ``admission_p99_ms`` (scheduled-arrival
-            to flush-completion latency percentiles), plus counters
+            to flush-completion latency percentiles, the flush's device
+            work included, over the :data:`RECENT_EVENTS` most recent
+            answered events), plus counters
             (``submitted``, ``accepted``, ``rejected``,
             ``rejection_cost``, ``events_folded``, ``flushes``, and
             ``flush_errors`` — flushes that raised, whose tickets failed).

@@ -30,6 +30,7 @@ from typing import Dict, List, Optional, Set, Tuple
 
 from repro.serving import wire
 from repro.serving.allocd import AllocDaemon
+from repro.spans import span
 
 
 class AllocServer:
@@ -144,7 +145,8 @@ class AllocServer:
         if mtype == "register_tenant":
             return self._on_register(msg, writer, tenants, cseq_by_seq)
         if mtype == "offer":
-            return self._on_offer(msg, writer, tenants, cseq_by_seq)
+            with span("wire.offer"):
+                return self._on_offer(msg, writer, tenants, cseq_by_seq)
         if mtype == "flush":
             return self._on_flush_req(msg, writer, tenants)
         if mtype == "drain":
@@ -231,20 +233,21 @@ class AllocServer:
         flush_seq = [0]
 
         def push(report, tickets):
-            seqmap = cseq_by_seq.get(name, {})
-            entries = [{"cseq": seqmap.pop(t.seq, None), "slot": t.slot}
-                       for t in tickets]
-            msg = {"type": "flush", "tenant": name,
-                   "flush_seq": flush_seq[0], "tickets": entries,
-                   "report": None if report is None
-                   else wire.encode_report(report)}
-            if report is None:
-                msg["error"] = "flush failed (epoch discarded)"
-            flush_seq[0] += 1
-            try:
-                self._send(writer, msg)
-            except (wire.WireError, ConnectionError):
-                self.daemon.detach_tenant(name)
+            with span("wire.push"):
+                seqmap = cseq_by_seq.get(name, {})
+                entries = [{"cseq": seqmap.pop(t.seq, None), "slot": t.slot}
+                           for t in tickets]
+                msg = {"type": "flush", "tenant": name,
+                       "flush_seq": flush_seq[0], "tickets": entries,
+                       "report": None if report is None
+                       else wire.encode_report(report)}
+                if report is None:
+                    msg["error"] = "flush failed (epoch discarded)"
+                flush_seq[0] += 1
+                try:
+                    self._send(writer, msg)
+                except (wire.WireError, ConnectionError):
+                    self.daemon.detach_tenant(name)
 
         return push
 
