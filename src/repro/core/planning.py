@@ -29,12 +29,14 @@ feasible design:
   feasibility = "deadline attainable under this design"), with Pareto
   frontier extraction and a cheapest-feasible-design query.
 
-CLI: ``python -m repro.launch.plan``; benchmark: ``benchmarks/plan_perf.py``
-(candidates/sec, gated by ``scripts/check_bench.py``); operator guide:
-``docs/OPERATIONS.md`` "Capacity planning".
+CLI: ``python -m repro.launch.plan``; benchmark: ``python3 bench/run.py
+--workload plan-1000c`` (candidates/s on the chip, PERF.md); operator
+guide: ``docs/OPERATIONS.md`` "Capacity planning".
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -48,7 +50,7 @@ from repro.core.engine import (CapacityEngine, Policies, RoundingPolicy,
                                SolverConfig, _cast_floats)
 from repro.core.profiles import sample_class_params
 from repro.core.traces import ARRIVAL_PROFILES
-from repro.core.types import Scenario, ScenarioBatch, derive, stack_scenarios
+from repro.core.types import Scenario, ScenarioBatch, derive, pad_scenario
 from repro.spans import span
 from repro.utils import fdtype
 
@@ -451,20 +453,55 @@ def _chunk_targets(chunk: int, cfg: SolverConfig) -> int:
     return sharding.padded_lane_count(chunk, cfg.mesh.devices.size)
 
 
-def _stack_chunk(part: Sequence[Candidate], n_max: int, target: int, dtype
-                 ) -> Tuple[ScenarioBatch, int]:
+@functools.partial(jax.jit, static_argnames="dtype")
+def _stack_lanes(leaves: Tuple[jnp.ndarray, ...], *, dtype) -> jnp.ndarray:
+    """One field of a chunk's candidates stacked along a new lane axis and
+    cast to ``dtype`` when floating (``None`` keeps it), in one compiled
+    program.
+
+    A program per field, not per chunk: on a TPU a program's set-up
+    (trace, lowering, compile or load) grows with its inputs, and one
+    program over every field of a chunk cost more set-up than all of its
+    chunks' stacking saved (PERF.md §6).  Fields of one shape and dtype
+    share a program.
+    """
+    out = jnp.stack(leaves)
+    return out if dtype is None else _cast_floats(out, dtype)
+
+
+def _stack_chunk(part: Sequence[Candidate], n_max: int, target: int, dtype,
+                 mesh=None) -> Tuple[ScenarioBatch, int]:
     """One chunk's solver input: its candidates' scenarios stacked to
-    ``n_max`` classes, inert-lane padded to ``target`` lanes and cast to
-    ``dtype`` (``None`` keeps theirs); returned with its real lane count."""
-    with span("plan.stack"):
-        batch = stack_scenarios([c.scenario for c in part], n_max=n_max)
-        real = batch.batch_size
-        batch = sharding.pad_batch_lanes(batch, target)
-        if dtype is not None:
-            batch = ScenarioBatch(
-                scenarios=_cast_floats(batch.scenarios, dtype),
-                mask=batch.mask, n_classes=batch.n_classes)
-    return batch, real
+    ``n_max`` classes, cast to ``dtype`` (``None`` keeps theirs),
+    inert-lane padded to ``target`` lanes and, with a ``mesh``, placed
+    with ``sharding.lane_sharding(mesh)``; returned with its real lane
+    count.
+
+    Bit for bit what ``stack_scenarios`` -> ``sharding.pad_batch_lanes``
+    -> ``_cast_floats`` give, without their per-candidate dispatches:
+    candidates narrower than ``n_max`` are class-padded by
+    ``pad_scenario`` (one device read of ``rho_bar`` each; the span's
+    ``ragged`` counts them), each field is one :func:`_stack_lanes` call,
+    and ``mask`` / ``n_classes`` come from the host-known class counts.
+    Sharded, one ``device_put`` of the stacked batch places it, which
+    makes the solver's own placement a no-op (an output sharded inside
+    the program would replicate every input leaf to every device).
+    """
+    ns = np.asarray([c.scenario.n for c in part])
+    ragged = int(np.count_nonzero(ns < n_max))
+    with span("plan.stack", ragged=ragged):
+        scns = [c.scenario if n == n_max else pad_scenario(c.scenario, n_max)
+                for c, n in zip(part, ns)]
+        fields = {f.name: _stack_lanes(tuple(getattr(s, f.name)
+                                             for s in scns), dtype=dtype)
+                  for f in dataclasses.fields(Scenario)}
+        batch = sharding.pad_batch_lanes(ScenarioBatch(
+            scenarios=Scenario(**fields),
+            mask=jnp.asarray(np.arange(n_max)[None, :] < ns[:, None]),
+            n_classes=jnp.asarray(ns)), target)
+        if mesh is not None:
+            batch = jax.device_put(batch, sharding.lane_sharding(mesh))
+    return batch, len(part)
 
 
 def _solve_cold(candidates: Sequence[Candidate], cfg: SolverConfig,
@@ -502,7 +539,7 @@ def _solve_cold(candidates: Sequence[Candidate], cfg: SolverConfig,
         part = candidates[start:start + chunk]
         with span("plan.chunk", lanes=len(part)):
             batch, real = _stack_chunk(part, n_max, target,
-                                       cfg.effective_dtype())
+                                       cfg.effective_dtype(), cfg.mesh)
             report = engine.solve(batch, check_feasible=False)
             sol = report.fractional
             with span("plan.pull"):
@@ -567,7 +604,8 @@ def _solve_warm(spec: PlanSpec, candidates: Sequence[Candidate],
         for d in range(D):
             part = [candidates[ci * D + d] for ci in chains]
             with span("plan.chunk", lanes=len(part)):
-                batch, real = _stack_chunk(part, n_max, target, dt)
+                batch, real = _stack_chunk(part, n_max, target, dt,
+                                           cfg.mesh)
                 init = game.cold_start(batch)
                 if prev_r is not None:
                     init = init._replace(

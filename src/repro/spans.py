@@ -22,6 +22,10 @@ PREFIX = "repro."
 
 #: Every span of the program, ``(name, layer)``: the layer is the module
 #: group of ``PERF.md``'s layer map that the span's code belongs to.
+#: Metadata: ``allocd.enqueue`` / ``allocd.fold`` carry ``seq``,
+#: ``allocd.flush`` ``tenant`` and ``events``, ``plan.chunk`` ``lanes``
+#: and ``plan.stack`` ``ragged`` (the chunk's lanes class-padded on the
+#: host, outside the compiled stack).
 SPANS = (
     ("wire.offer", "wire"),
     ("wire.push", "wire"),
@@ -46,7 +50,7 @@ def span(name: str, **meta) -> TraceAnnotation:
         A name of :data:`SPANS`.
     **meta : int or str
         The layer's counts at this boundary (a ticket's ``seq``, a chunk's
-        ``lanes``).
+        ``lanes``, a stack's ``ragged``).
 
     Returns
     -------

@@ -12,16 +12,17 @@ admission daemon via bit-compatible re-exports."""
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
-from repro.core import sharding, traces
+from repro.core import planning, sharding, traces
 from repro.core.engine import (CapacityEngine, Policies, RoundingPolicy,
-                               SolverConfig)
+                               SolverConfig, _cast_floats)
 from repro.core.planning import (PlanSpec, VMTier, generate_grid,
                                  solve_plan)
-from repro.core.types import stack_scenarios
+from repro.core.types import ScenarioBatch, stack_scenarios
 from repro.serving import allocd
 
 SPEC = PlanSpec(
@@ -31,6 +32,10 @@ SPEC = PlanSpec(
     deadline_scales=(0.9, 1.0, 1.15), penalty_scales=(1.0,), seed=3)
 
 RESULT_FIELDS = ("cost", "penalty", "total", "r", "iters", "feasible")
+
+#: The same design space at one class fewer: candidates the planner must
+#: class-pad on the host when they share a chunk with ``SPEC``'s.
+NARROW = dataclasses.replace(SPEC, n_classes=2)
 
 
 @pytest.fixture(scope="module")
@@ -154,6 +159,100 @@ def test_solve_plan_rejects_bad_args(grid):
         solve_plan(grid, chunk=0)
     with pytest.raises(ValueError, match="warm"):
         solve_plan(grid, warm_start=True)     # plain list has no axes
+
+
+# --------------------------------------------------------------------------
+# Chunk stacking: compiled per field, bit-equal to the eager stack
+# --------------------------------------------------------------------------
+
+def eager_stack(part, n_max, target, dtype):
+    """The eager composition the planner's stack program replaces:
+    ``stack_scenarios`` -> ``pad_batch_lanes`` -> ``_cast_floats``."""
+    batch = stack_scenarios([c.scenario for c in part], n_max=n_max)
+    batch = sharding.pad_batch_lanes(batch, target)
+    if dtype is None:
+        return batch
+    return ScenarioBatch(scenarios=_cast_floats(batch.scenarios, dtype),
+                         mask=batch.mask, n_classes=batch.n_classes)
+
+
+@pytest.mark.parametrize("case", ["uniform", "ragged", "inert", "cast",
+                                  "mesh"])
+def test_stack_chunk_bit_equal_eager(grid, case):
+    """Every leaf, ``mask`` and ``n_classes`` equal the eager stack's bit
+    for bit and in dtype, for uniform and ragged lanes, inert lanes, a
+    float64 -> float32 cast and a lane-sharded 4-device mesh."""
+    narrow = generate_grid(NARROW)
+    part = list(grid[:5])
+    target, dtype, mesh = 5, None, None
+    if case in ("ragged", "mesh"):
+        part = [grid[0], narrow[1], grid[2], narrow[3], grid[4]]
+    if case == "inert":
+        target = 8
+    if case == "cast":
+        dtype = jnp.float32
+    if case == "mesh":
+        mesh = sharding.lane_mesh(4)
+        target = sharding.padded_lane_count(len(part), 4)
+    got, real = planning._stack_chunk(part, 3, target, dtype, mesh)
+    want = eager_stack(part, 3, target, dtype)
+    assert real == len(part) and got.batch_size == target
+    got_leaves = jax.tree_util.tree_leaves(got)
+    want_leaves = jax.tree_util.tree_leaves(want)
+    assert len(got_leaves) == len(want_leaves)
+    for g, w in zip(got_leaves, want_leaves):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    if dtype is not None:
+        assert got.scenarios.A.dtype == jnp.float32
+        assert got.scenarios.A.dtype != grid[0].scenario.A.dtype
+    if case in ("ragged", "mesh"):
+        # a class-padded lane bids its own rho_bar in the padded slots
+        rho_up = np.asarray(got.scenarios.rho_up)
+        rho_bar = np.asarray(got.scenarios.rho_bar)
+        for b in (1, 3):
+            assert not np.asarray(got.mask)[b, 2]
+            assert rho_up[b, 2] == rho_bar[b]
+    if mesh is not None:
+        sh = sharding.lane_sharding(mesh)
+        assert all(leaf.sharding == sh for leaf in got_leaves)
+
+
+def test_stack_program_compiles_once_per_width(grid):
+    """Two chunks of equal width share the stack programs (one for the
+    per-class fields, one for the scalars), and each answer is its own
+    candidates' (nothing is kept across calls)."""
+    planning._stack_lanes.clear_cache()
+    first = solve_plan(grid[:6], chunk=6)
+    assert planning._stack_lanes._cache_size() == 2
+    second = solve_plan(grid[6:], chunk=6)
+    whole = solve_plan(grid, chunk=6)
+    assert planning._stack_lanes._cache_size() == 2
+    for k in RESULT_FIELDS:
+        np.testing.assert_array_equal(getattr(first, k),
+                                      getattr(whole, k)[:6], err_msg=k)
+        np.testing.assert_array_equal(getattr(second, k),
+                                      getattr(whole, k)[6:], err_msg=k)
+    assert not np.array_equal(first.total, second.total)
+
+
+@pytest.mark.parametrize("narrow_lanes", [0, 1, 3])
+def test_stack_span_counts_ragged_lanes(grid, monkeypatch, narrow_lanes):
+    """``repro.plan.stack`` carries ``ragged``: the lanes padded on the
+    host, 0 for a uniform grid and k for k narrow candidates."""
+    seen = []
+    real_span = planning.span
+
+    def spy(name, **meta):
+        if name == "plan.stack":
+            seen.append(meta["ragged"])
+        return real_span(name, **meta)
+
+    monkeypatch.setattr(planning, "span", spy)
+    part = (generate_grid(NARROW)[:narrow_lanes]
+            + list(grid[narrow_lanes:6]))
+    rep = solve_plan(part, chunk=len(part))
+    assert seen == [narrow_lanes] and rep.n_chunks == 1
 
 
 # --------------------------------------------------------------------------

@@ -167,6 +167,9 @@ def test_plan_chunks_count_their_lanes(tmp_path, warm_start):
     for c in chunks:
         assert sorted(k.name for k in c.children) == ["plan.pull",
                                                       "plan.stack"]
+        # a uniform grid: no lane is class-padded on the host
+        stack = next(k for k in c.children if k.name == "plan.stack")
+        assert stack.meta["ragged"] == 0
     red = bspans.reduce(planes)
     assert red["lanes"] == SPEC.n_candidates
     for f in (bspans.plan_stack_us, bspans.plan_pull_us):
