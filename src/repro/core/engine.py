@@ -1206,11 +1206,12 @@ class WindowSession:
     def drain(self) -> List[Optional[int]]:
         """Fold every buffered event into the window WITHOUT re-solving.
 
-        One coalesced ``AdmissionWindow.apply_epoch`` (one scatter per
-        Scenario field however many events are pending); the window is
-        left dirty for the next :meth:`solve` / :meth:`flush`.  Drivers
-        that need arrival slot grants before deciding further events (the
-        fleet layer does) call this directly.
+        One ``AdmissionWindow.apply_epoch`` however many events are
+        pending, one event included: one upload of the packed epoch and
+        one compiled commit program, with no read back from the device.
+        The window is left dirty for the next :meth:`solve` /
+        :meth:`flush`.  Drivers that need arrival slot grants before
+        deciding further events (the fleet layer does) call this directly.
 
         Returns
         -------
@@ -1220,14 +1221,7 @@ class WindowSession:
         """
         if not self._pending:
             return []
-        if len(self._pending) == 1:
-            # single-event fast path: skip the epoch simulation entirely
-            # (apply_epoch is proven bit-equal to sequential apply, so this
-            # changes dispatch cost only — per-event streaming is
-            # dispatch-bound on CPU)
-            slots = [self.window.apply(self._pending[0])]
-        else:
-            slots = self.window.apply_epoch(self._pending)
+        slots = self.window.apply_epoch(self._pending)
         self.events_folded += len(self._pending)
         self._pending = []
         self.last_slots = slots

@@ -28,14 +28,15 @@ engine (``game.solve_distributed_batch``) into that runtime system:
   only the dirty lanes' work.
 
 * Windows are *dynamic*: :meth:`AdmissionWindow.apply_epoch` folds any
-  number of events into one atomic, coalesced update (one scatter per
-  Scenario field instead of one dispatch per event — the CPU dispatch
-  bottleneck PR 3 recorded); :class:`EventEpoch` + :class:`FlushPolicy`
-  decide *when* to re-solve (count / dirty-fraction triggers); lanes can be
-  added and removed between solves (:meth:`AdmissionWindow.add_lane` /
-  :meth:`AdmissionWindow.remove_lane`); and :meth:`AdmissionWindow.compact`
-  re-packs sparse long-lived windows, remapping the stored equilibrium so
-  frozen lanes stay frozen across the re-layout.
+  number of events, one included, into one atomic update (one upload of
+  host-packed operands and one compiled commit program that derives, writes
+  and refreshes on the device, with no read back); :class:`EventEpoch` +
+  :class:`FlushPolicy` decide *when* to re-solve (count / dirty-fraction
+  triggers); lanes can be added and removed between solves
+  (:meth:`AdmissionWindow.add_lane` / :meth:`AdmissionWindow.remove_lane`);
+  and :meth:`AdmissionWindow.compact` re-packs sparse long-lived windows,
+  remapping the stored equilibrium so frozen lanes stay frozen across the
+  re-layout.
 
 The user-facing layer is :class:`repro.core.engine.CapacityEngine` /
 :class:`repro.core.engine.WindowSession` (``open_window`` -> ``apply`` /
@@ -66,144 +67,80 @@ from repro.core.types import (RAW_CLASS_FIELDS, CapacityChange, ClassArrival,
                               neutral_class_values, pad_scenario,
                               stack_scenarios)
 
-#: Per-class Scenario fields (raw + derived) scattered on every class write.
+#: Per-class Scenario fields (raw + derived) written on every class write.
 _CLASS_FIELDS = tuple(neutral_class_values(0.0).keys())
+#: Raw values of a vacated slot's row in an epoch's staged rows: finite
+#: inputs for the in-program derive, whose result the slot never keeps.
+_NEUTRAL_RAW = tuple(neutral_class_values(1.0)[f] for f in RAW_CLASS_FIELDS)
 
 
 def _pad_idx(idx: list) -> list:
     """Pad a scatter-index list to the next power of two by repeating its
     last entry.  Scattering the same value to a duplicated index is
-    idempotent, and the bucketed shapes bound how many signatures the
-    jitted scatter helpers below ever compile — epochs of any size hit a
-    warm compile cache after the first few flushes."""
+    idempotent, and the bucketed shapes bound how many signatures
+    :func:`_epoch_commit` ever compiles — epochs of any size hit a warm
+    compile cache after the first few flushes."""
     if not idx:
         return idx
     return idx + [idx[-1]] * ((1 << (len(idx) - 1).bit_length()) - len(idx))
 
 
-@jax.jit
-def _scatter_class_fields(scn: Scenario, li, si, vals) -> Scenario:
-    """One fused scatter updating every per-class field at (li, si).
+def _upload(operands):
+    """Send one epoch's host-packed operands to the device in ONE call.
 
-    The write path of both the per-event and the coalesced engines: doing
-    all ~20 field updates inside one jitted program costs ONE dispatch per
-    event epoch instead of one per (field, event) — on CPU the dispatch,
-    not the math, is the streaming bottleneck (ROADMAP caveat from PR 3).
+    The only host->device transfer of the write path: every array of the
+    epoch rides one ``jax.device_put`` of the operand tree, where a
+    ``jnp.asarray`` per field would pay a PJRT transfer and allocation
+    each.
     """
-    return scn.replace(**{f: getattr(scn, f).at[li, si].set(vals[f])
-                          for f in _CLASS_FIELDS})
+    return jax.device_put(operands)
 
 
 @jax.jit
-def _epoch_commit(scn: Scenario, mask_dev, state_r, li, si, vals, occ,
-                  R_lanes, R_vals, hat_lanes, hat_rows):
-    """The WHOLE epoch commit as one jitted program: class-field scatter,
-    resident-mask-mirror scatter, vacated-slot warm-state zeroing, lane
-    capacity updates and the rho_hat refresh.
+def _epoch_commit(scn: Scenario, mask_dev, state_r, classes, capacity):
+    """The WHOLE epoch commit as one jitted program: derive of the staged
+    classes, class-field scatter, resident-mask-mirror scatter,
+    vacated-slot warm-state zeroing, lane capacity updates and the
+    rho_hat refresh.
 
-    Any of the sub-updates may be absent (``None`` operands prune that
-    branch at trace time; each presence combination compiles once).  One
-    fused dispatch instead of up to five matters twice over: on CPU the
-    dispatch is the streaming bottleneck (PR 3 caveat), and on a
-    device-resident window every operand is lane-sharded, so each dispatch
-    costs a full multi-device execution round.  Value-identical to running
-    :func:`_scatter_class_fields` / :func:`_refresh_hats` and the mask/
-    state/R scatters back-to-back (same scatter order, disjoint or
-    idempotent writes).
+    ``classes`` is ``(raw, idx, rows)``: the staged slots' raw parameters
+    as (K, 9) rows in :data:`RAW_CLASS_FIELDS` order, their
+    ``(lane, slot, occupied)`` as (K, 3) ints, and each staged slot's
+    lane row of the host mask after the epoch (K, n_max).  Occupied slots
+    take :func:`~repro.core.types.derive` of their row, vacated slots the
+    neutral values with ``rho_up`` = their lane's ``rho_bar``.
+    ``capacity`` is ``(lanes, R)``.  Absent parts are ``None`` and prune
+    their branch at trace time (each presence combination compiles once).
+    Nothing is read back: the host keeps the raw parameters and the mask.
     """
-    if li is not None:
-        scn = scn.replace(**{f: getattr(scn, f).at[li, si].set(vals[f])
-                             for f in _CLASS_FIELDS})
+    if classes is not None:
+        raw, idx, rows = classes
+        li, si, occ = idx[:, 0], idx[:, 1], idx[:, 2] != 0
+        derived = derive(*raw.T, R=0.0, rho_bar=0.0)
+        neutral = neutral_class_values(0.0)
+        neutral["rho_up"] = scn.rho_bar[li]
+        scn = scn.replace(**{
+            f: getattr(scn, f).at[li, si].set(
+                jnp.where(occ, getattr(derived, f), neutral[f]))
+            for f in _CLASS_FIELDS})
         if mask_dev is not None:
             mask_dev = mask_dev.at[li, si].set(occ)
         if state_r is not None:
             # vacated slots restart from 0; occupied staged slots keep their
             # stored allocation (their lane goes dirty and restarts cold
-            # anyway) — bit-equal to the old vacated-only scatter
+            # anyway)
             state_r = state_r.at[li, si].set(
                 jnp.where(occ, state_r[li, si], jnp.zeros((), state_r.dtype)))
-    if R_lanes is not None:
-        scn = scn.replace(R=scn.R.at[R_lanes].set(R_vals))
-    if hat_lanes is not None:
-        hats = jnp.max(jnp.where(hat_rows, scn.rho_up[hat_lanes],
-                                 scn.rho_bar[hat_lanes][:, None]), axis=1)
-        scn = scn.replace(rho_hat=scn.rho_hat.at[hat_lanes].set(hats))
+        # rho_hat = max_i rho_up over ADMITTED classes (paper (P5e) interval
+        # end); an empty lane degenerates to the single candidate rho_bar.
+        # Rows of one lane are equal, so duplicate lanes write one value.
+        hats = jnp.max(jnp.where(rows, scn.rho_up[li],
+                                 scn.rho_bar[li][:, None]), axis=1)
+        scn = scn.replace(rho_hat=scn.rho_hat.at[li].set(hats))
+    if capacity is not None:
+        lanes, R = capacity
+        scn = scn.replace(R=scn.R.at[lanes].set(R))
     return scn, mask_dev, state_r
-
-
-@jax.jit
-def _refresh_hats(scn: Scenario, lanes, rows) -> Scenario:
-    """Recompute rho_hat = max over admitted rho_up for the given lanes.
-
-    ``rows`` carries the lanes' occupancy-mask rows; an empty lane
-    degenerates to the single candidate rho_bar (paper (P5e) interval end).
-    Fused + jitted for the same dispatch-amortization reason as
-    :func:`_scatter_class_fields`.
-    """
-    hats = jnp.max(jnp.where(rows, scn.rho_up[lanes],
-                             scn.rho_bar[lanes][:, None]), axis=1)
-    return scn.replace(rho_hat=scn.rho_hat.at[lanes].set(hats))
-
-
-def _derive_class(params: dict, dtype) -> dict:
-    """Derived per-class constants (Props. 3.3, Eqs. 7/8/17/18) for ONE class.
-
-    Parameters
-    ----------
-    params : dict
-        Raw per-class scalars; keys exactly :data:`RAW_CLASS_FIELDS`.
-    dtype : jnp.dtype
-        Float dtype of the window's leaves.
-
-    Returns
-    -------
-    dict
-        Field name -> python float for every per-class field of
-        :class:`Scenario` (the raw values plus the derived constants),
-        computed by the same :func:`repro.core.types.derive` closed forms
-        the batch constructor uses.
-    """
-    return {f: float(v[0]) for f, v in _derive_classes([params],
-                                                       dtype).items()}
-
-
-#: jitted :func:`repro.core.types.derive` — the streaming write paths call
-#: it per event / per epoch, where eager elementwise dispatch would dominate.
-_derive_jit = jax.jit(derive)
-
-
-def _derive_classes(params_list: Sequence[dict], dtype) -> Dict[str, np.ndarray]:
-    """Derived constants for MANY classes in one device round-trip.
-
-    The coalesced-epoch analog of :func:`_derive_class`: :func:`derive` is
-    elementwise in its per-class inputs, so stacking the raw dicts and
-    deriving once yields values bit-identical to T per-class calls while
-    paying one dispatch + one host transfer per *field* instead of per
-    (field, class).
-
-    Parameters
-    ----------
-    params_list : Sequence[dict]
-        Raw per-class scalar dicts; keys exactly :data:`RAW_CLASS_FIELDS`.
-    dtype : jnp.dtype
-        Float dtype of the window's leaves.
-
-    Returns
-    -------
-    dict
-        Field name -> (T,) numpy array for every per-class field of
-        :class:`Scenario`, aligned with ``params_list``.
-    """
-    for params in params_list:
-        missing = set(RAW_CLASS_FIELDS) - set(params)
-        if missing:
-            raise ValueError(f"class params missing fields {sorted(missing)}")
-    many = _derive_jit(**{k: jnp.asarray([p[k] for p in params_list], dtype)
-                          for k in RAW_CLASS_FIELDS},
-                       R=jnp.asarray(0.0, dtype),
-                       rho_bar=jnp.asarray(0.0, dtype))
-    host = jax.device_get([getattr(many, f) for f in _CLASS_FIELDS])
-    return dict(zip(_CLASS_FIELDS, host))
 
 
 class AdmissionWindow:
@@ -260,12 +197,6 @@ class AdmissionWindow:
         self._resident_mesh = None
         self._mask_dev = None
         self._n_classes_dev = self._n_classes_host = None
-        # host cache of the per-lane unit chip cost: vacated-slot neutral
-        # values need rho_bar per event epoch, and reading it off a
-        # (possibly mesh-sharded) device array would synchronise every
-        # flush.  Only __init__/add_lane/remove_lane ever change it.
-        self._rho_bar_host = np.asarray(batch.scenarios.rho_bar,
-                                        float).copy()
         self.growth_factor = float(growth_factor)
         self.dirty = np.zeros(self.batch_size, bool)
         # per-lane memo of the exact centralized (P3) total, invalidated by
@@ -517,6 +448,9 @@ class AdmissionWindow:
     def apply(self, event: StreamEvent) -> Optional[int]:
         """Apply one event; returns the assigned slot for arrivals.
 
+        A one-event :meth:`apply_epoch`: the same validation, the same
+        single upload and the same commit program.
+
         Parameters
         ----------
         event : StreamEvent
@@ -527,17 +461,7 @@ class AdmissionWindow:
         int or None
             The slot granted to a :class:`ClassArrival`, else None.
         """
-        if isinstance(event, ClassArrival):
-            return self.arrive(event.lane, **event.params)
-        if isinstance(event, ClassDeparture):
-            self.depart(event.lane, event.slot)
-        elif isinstance(event, SLAEdit):
-            self.edit(event.lane, event.slot, **event.updates)
-        elif isinstance(event, CapacityChange):
-            self.set_capacity(event.lane, event.R)
-        else:
-            raise TypeError(f"unknown event {event!r}")
-        return None
+        return self.apply_epoch([event])[0]
 
     def apply_epoch(self, events: Sequence[StreamEvent]) -> List[Optional[int]]:
         """Fold MANY events into one atomic, coalesced window update.
@@ -545,16 +469,16 @@ class AdmissionWindow:
         Numerically identical to applying ``events`` one by one with
         :meth:`apply` (same slot assignments, same growth schedule, same
         written values — the per-slot constants come from the same
-        :func:`derive` closed forms), but the device work is *coalesced*:
-        the whole epoch commits in ONE fused dispatch
-        (:func:`_epoch_commit`: every class field, the resident mask
-        mirror, vacated warm-state slots, lane capacities and the rho_hat
-        refresh), so an epoch of K events costs one dispatch instead of
-        ~20·K.  This is the dispatch amortization that makes coalesced
-        re-solve epochs (:class:`EventEpoch`, ``allocator.solve_coalesced``)
-        pay off on dispatch-bound backends — and it is what keeps
-        device-resident windows cheap, where every dispatch is a full
-        multi-device execution round.
+        :func:`derive` closed forms).  The write path is the same for
+        every epoch, one event or many: the host packs the staged raw
+        rows, their slots and the capacity updates into a few numpy
+        arrays, sends them in ONE upload (:func:`_upload`), and ONE
+        compiled program (:func:`_epoch_commit`) derives the classes'
+        constants and writes every class field, the resident mask mirror,
+        vacated warm-state slots, lane capacities and the rho_hat refresh.
+        Nothing is read back from the device.  On a device-resident
+        window every dispatch is a full multi-device execution round, so
+        the one program matters there twice over.
 
         The update is atomic: events are validated against a host-side
         simulation of the whole epoch first, so an invalid event (unknown
@@ -580,7 +504,7 @@ class AdmissionWindow:
         sim_mask = self._mask.copy()
         n_max, B = self.n_max, self.batch_size
         staged: Dict[Tuple[int, int], Optional[dict]] = {}  # None = vacated
-        vacated: Set[Tuple[int, int]] = set()
+        vacated = False
         new_R: Dict[int, float] = {}
         granted: List[Optional[int]] = []
         for ev in events:
@@ -610,7 +534,7 @@ class AdmissionWindow:
                         f"(lane={ev.lane}, slot={ev.slot}) holds no class")
                 sim_mask[ev.lane, ev.slot] = False
                 staged[(ev.lane, ev.slot)] = None
-                vacated.add((ev.lane, ev.slot))
+                vacated = True
             elif isinstance(ev, SLAEdit):
                 self._check_lane(ev.lane)
                 if not 0 <= ev.slot < n_max or not sim_mask[ev.lane, ev.slot]:
@@ -629,65 +553,46 @@ class AdmissionWindow:
             else:
                 raise TypeError(f"unknown event {ev!r}")
 
-        # ---- commit: grow once, host bookkeeping, then ONE fused dispatch
+        # ---- pack on the host (power-of-two buckets bound the variants)
+        dt = np.dtype(self._scn.A.dtype)
+        classes = capacity = None
+        if staged:
+            keys = _pad_idx(sorted(staged))
+            raw = np.asarray(
+                [_NEUTRAL_RAW if staged[k] is None
+                 else [staged[k][f] for f in RAW_CLASS_FIELDS]
+                 for k in keys], dt)
+            idx = np.asarray([(l, s, staged[(l, s)] is not None)
+                              for l, s in keys], np.int32)
+            classes = (raw, idx, sim_mask[idx[:, 0]])
+        if new_R:
+            lanes = _pad_idx(sorted(new_R))
+            capacity = (np.asarray(lanes, np.int32),
+                        np.asarray([new_R[l] for l in lanes], dt))
+
+        # ---- commit: grow once, host bookkeeping, ONE upload + ONE program
         if n_max > self.n_max:
             self.grow(n_max)
-        dt = self._scn.A.dtype
-        li = si = vals_dev = occ_dev = state_r = None
-        if staged:
-            keys = sorted(staged)
-            rho_bar_np = self._rho_bar_host
-            neutral = neutral_class_values(0.0)
-            vals = {f: np.full(len(keys), neutral[f], np.dtype(dt))
-                    for f in _CLASS_FIELDS}
-            for i, k in enumerate(keys):            # vacated slots go neutral
-                if staged[k] is None:
-                    vals["rho_up"][i] = rho_bar_np[k[0]]
-            occ_pos = [i for i, k in enumerate(keys) if staged[k] is not None]
-            if occ_pos:
-                opad = _pad_idx(occ_pos)          # bucket the derive, too
-                derived = _derive_classes([staged[keys[i]] for i in opad], dt)
-                for f in _CLASS_FIELDS:
-                    vals[f][occ_pos] = derived[f][:len(occ_pos)]
-            pidx = _pad_idx(list(range(len(keys))))   # shape-bucketed scatter
-            li = jnp.asarray([keys[i][0] for i in pidx])
-            si = jnp.asarray([keys[i][1] for i in pidx])
-            vals_dev = {f: jnp.asarray(vals[f][pidx], dt)
-                        for f in _CLASS_FIELDS}
-            occ_dev = jnp.asarray(
-                np.asarray([staged[keys[i]] is not None for i in pidx]))
-            for k in keys:
-                occupied = staged[k] is not None
-                self._mask[k] = occupied
-                if occupied:
-                    self._raw[k] = dict(staged[k])
-                else:
-                    self._raw.pop(k, None)
-            if vacated and self._state is not None:
-                state_r = self._state.r
-        R_lanes = R_vals = None
-        if new_R:
-            lanes_R = _pad_idx(sorted(new_R))
-            R_lanes = jnp.asarray(lanes_R)
-            R_vals = jnp.asarray([new_R[l] for l in lanes_R], dt)
-        class_lanes = sorted({k[0] for k in staged})
-        hat_lanes = hat_rows = None
-        if class_lanes:
-            padded_lanes = _pad_idx(class_lanes)
-            hat_lanes = jnp.asarray(padded_lanes)
-            hat_rows = jnp.asarray(self._mask[padded_lanes])
+        self._mask = sim_mask
+        for k, params in staged.items():
+            if params is None:
+                self._raw.pop(k, None)
+            else:
+                self._raw[k] = dict(params)
         if staged or new_R:
-            scn, mask_dev, new_state_r = _epoch_commit(
+            state_r = (self._state.r if vacated and self._state is not None
+                       else None)
+            scn, mask_dev, state_r = _epoch_commit(
                 self._scn, self._mask_dev if staged else None, state_r,
-                li, si, vals_dev, occ_dev, R_lanes, R_vals,
-                hat_lanes, hat_rows)
+                *_upload((classes, capacity)))
             self._scn = scn
             if staged and self._mask_dev is not None:
                 self._mask_dev = mask_dev
-            if new_state_r is not None:
-                self._state = self._state._replace(r=new_state_r)
-        for lane in {*class_lanes, *new_R}:
-            self._mark_dirty(lane)
+            if state_r is not None:
+                self._state = self._state._replace(r=state_r)
+        for lane in {*(k[0] for k in staged), *new_R}:
+            self.dirty[lane] = True
+            self.baseline_stale[lane] = True
         return granted
 
     def arrive(self, lane: int, **params) -> int:
@@ -707,40 +612,11 @@ class AdmissionWindow:
             The slot index granted — the lowest free slot; the window grows
             (repads every leaf) only when the lane's row is full.
         """
-        self._check_lane(lane)
-        missing = set(RAW_CLASS_FIELDS) - set(params)
-        if missing:
-            # validate BEFORE any mutation: an aborted admission must leave
-            # both the host book-keeping and (for resident windows) the
-            # device buffers exactly at the last consistent state
-            raise ValueError(f"class params missing fields {sorted(missing)}")
-        free = np.flatnonzero(~self._mask[lane])
-        if free.size == 0:
-            self.grow(grown_n_max(self.n_max, self.growth_factor))
-            free = np.flatnonzero(~self._mask[lane])
-        slot = int(free[0])
-        self._raw[(lane, slot)] = dict(params)
-        self._write_class(lane, slot, dict(params))
-        self._set_mask(lane, slot, True)
-        self._refresh_rho_hat(lane)
-        self._mark_dirty(lane)
-        return slot
+        return self.apply(ClassArrival(lane=lane, params=params))
 
     def depart(self, lane: int, slot: int) -> None:
         """Remove the class at (lane, slot); the slot becomes recyclable."""
-        self._check_slot(lane, slot)
-        dt = self._scn.A.dtype
-        neutral = neutral_class_values(float(self._rho_bar_host[lane]))
-        self._scn = _scatter_class_fields(
-            self._scn, jnp.asarray([lane]), jnp.asarray([slot]),
-            {f: jnp.asarray([neutral[f]], dt) for f in _CLASS_FIELDS})
-        self._set_mask(lane, slot, False)
-        self._raw.pop((lane, slot), None)
-        self._refresh_rho_hat(lane)
-        if self._state is not None:
-            self._state = self._state._replace(
-                r=self._state.r.at[lane, slot].set(0.0))
-        self._mark_dirty(lane)
+        self.apply(ClassDeparture(lane=lane, slot=slot))
 
     def edit(self, lane: int, slot: int, **updates) -> None:
         """Renegotiate the SLA / profile of the class at (lane, slot).
@@ -753,22 +629,11 @@ class AdmissionWindow:
             Subset of :data:`RAW_CLASS_FIELDS` to overwrite; derived
             constants are recomputed from the merged raw parameters.
         """
-        self._check_slot(lane, slot)
-        bad = set(updates) - set(RAW_CLASS_FIELDS)
-        if bad:
-            raise ValueError(f"unknown raw fields {sorted(bad)}")
-        merged = {**self._raw[(lane, slot)], **updates}
-        self._raw[(lane, slot)] = merged
-        self._write_class(lane, slot, merged)
-        self._refresh_rho_hat(lane)
-        self._mark_dirty(lane)
+        self.apply(SLAEdit(lane=lane, slot=slot, updates=updates))
 
     def set_capacity(self, lane: int, R: float) -> None:
         """Set lane capacity R (node failures / restores, paper Fig. 2)."""
-        self._check_lane(lane)
-        self._scn = self._scn.replace(
-            R=self._scn.R.at[lane].set(float(R)))
-        self._mark_dirty(lane)
+        self.apply(CapacityChange(lane=lane, R=R))
 
     def grow(self, new_n_max: int) -> None:
         """Repad every (B, n_max) leaf to ``new_n_max`` columns.
@@ -887,7 +752,6 @@ class AdmissionWindow:
             self.dirty = np.append(self.dirty, True)
             self.baseline_totals = np.append(self.baseline_totals, np.nan)
             self.baseline_stale = np.append(self.baseline_stale, True)
-            self._rho_bar_host = np.asarray(self._scn.rho_bar, float).copy()
         return b
 
     def remove_lane(self, lane: int) -> None:
@@ -920,7 +784,6 @@ class AdmissionWindow:
                     solved=jnp.delete(st.solved, lane))
             self._raw = {(b - (b > lane), s): raw
                          for (b, s), raw in self._raw.items() if b != lane}
-            self._rho_bar_host = np.delete(self._rho_bar_host, lane)
 
     def compact(self, *, n_max: Optional[int] = None) -> np.ndarray:
         """Re-pack every lane's admitted classes into a slot prefix.
@@ -1054,40 +917,9 @@ class AdmissionWindow:
         self.dirty[:] = False
 
     # -------------------------------------------------------------- internals
-    def _set_mask(self, lane: int, slot: int, occupied: bool) -> None:
-        """One slot's occupancy, kept in sync on the host mask and (when
-        resident) the device mirror — the single-event write path."""
-        self._mask[lane, slot] = occupied
-        if self._mask_dev is not None:
-            self._mask_dev = self._mask_dev.at[lane, slot].set(occupied)
-
-    def _mark_dirty(self, lane: int) -> None:
-        self.dirty[lane] = True
-        self.baseline_stale[lane] = True
-
     def _check_lane(self, lane: int) -> None:
         if not 0 <= lane < self.batch_size:
             raise IndexError(f"lane {lane} out of range [0, {self.batch_size})")
-
-    def _check_slot(self, lane: int, slot: int) -> None:
-        self._check_lane(lane)
-        if not 0 <= slot < self.n_max or not self._mask[lane, slot]:
-            raise IndexError(f"(lane={lane}, slot={slot}) holds no class")
-
-    def _write_class(self, lane: int, slot: int, raw: dict) -> None:
-        dt = self._scn.A.dtype
-        vals = _derive_class(raw, dt)
-        self._scn = _scatter_class_fields(
-            self._scn, jnp.asarray([lane]), jnp.asarray([slot]),
-            {f: jnp.asarray([vals[f]], dt) for f in _CLASS_FIELDS})
-
-    def _refresh_rho_hat(self, lane: int) -> None:
-        # rho_hat = max_i rho_up over ADMITTED classes (paper (P5e) interval
-        # end); an empty lane degenerates to the single candidate rho_bar.
-        # copy: ``_mask[lane][None]`` is a numpy view and jnp.asarray may
-        # zero-copy it — the jitted refresh must read a snapshot
-        self._scn = _refresh_hats(self._scn, jnp.asarray([lane]),
-                                  jnp.asarray(self._mask[lane][None].copy()))
 
 
 def grown_n_max(n_max: int, growth_factor: float) -> int:
@@ -1257,9 +1089,10 @@ class EventEpoch:
 
     The coalescing layer between per-event streaming (PR 2) and the
     operator's cadence policy: events buffer on the host (zero device
-    work), and :meth:`flush` folds them into the window with ONE scatter
-    per Scenario field (:meth:`AdmissionWindow.apply_epoch`) followed by
-    ONE warm-started ``solve_streaming`` over the union of dirtied lanes.
+    work), and :meth:`flush` folds them into the window with ONE upload
+    and ONE commit program (:meth:`AdmissionWindow.apply_epoch`) followed
+    by ONE warm-started ``solve_streaming`` over the union of dirtied
+    lanes.
     Replaying a trace through epochs lands on exactly the per-event
     equilibria at every flush boundary: a lane dirtied anywhere in the
     epoch restarts from the cold Algorithm 4.1 init on its *final*

@@ -176,7 +176,14 @@ def test_property_apply_epoch_atomic_under_any_prefix(seed, n_good,
     w = make_window(ns=(3, 4), n_max=8)
     good = sample_event_trace(seed, w, n_good, p_depart=0.0, p_edit=0.0,
                               p_capacity=0.0) if n_good else []
-    bad = {"empty-slot": ClassDeparture(lane=0, slot=7),
+    # the prefix's arrivals may fill any fixed slot, so the empty slot is
+    # read off a twin window that has applied the prefix (the slot just
+    # past the row when the prefix leaves every slot taken)
+    twin = make_window(ns=(3, 4), n_max=8)
+    twin.apply_epoch(good)
+    free = np.argwhere(~twin._mask)
+    lane, empty = free[0] if len(free) else (0, twin.n_max)
+    bad = {"empty-slot": ClassDeparture(lane=int(lane), slot=int(empty)),
            "bad-field": SLAEdit(lane=0, slot=0, updates={"nope": 1.0}),
            "missing": ClassArrival(lane=0, params={"A": 1.0}),
            "bad-lane": ClassDeparture(lane=99, slot=0)}[bad_kind]
